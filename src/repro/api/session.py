@@ -597,7 +597,12 @@ class Session:
                      train: bool, planned=None):
         """Shared partition + store + shard-fns construction."""
         shard_plan = sg.build_plan(cfg)
-        host = sg.prepare_host_params(cfg, jax.tree.map(np.asarray, params))
+        # the partitioner reads shapes and dtypes only: zero-stride stand-ins
+        # spare host DRAM a copy of the weights (fetching a device array
+        # also caches a host copy on it for as long as it lives)
+        host = sg.prepare_host_params(cfg, jax.tree.map(
+            lambda a: np.broadcast_to(np.zeros((), a.dtype), np.shape(a)),
+            params))
         # shards are sized against the budget MINUS the serve KV-page cap:
         # pages charge the same ledger promotions do, so a shard planned
         # for the full budget would blow _check_budget mid-run whenever
@@ -1113,12 +1118,13 @@ def _run_spmd(job: SpmdTrainJob) -> dict:
         params, opt_state, metrics = step_fn(params, opt_state, batch)
         if step % job.log_every == 0 or step == job.steps - 1:
             loss = float(metrics["loss"])
+            gnorm = float(metrics["grad_norm"])
             dt = time.perf_counter() - t0
             tok_s = job.batch * job.seq * (step + 1) / dt
             print(f"step {step:5d}  loss {loss:8.4f}  "
-                  f"gnorm {float(metrics['grad_norm']):7.3f}  "
-                  f"{tok_s:9.0f} tok/s")
-            history.append({"step": step, "loss": loss})
+                  f"gnorm {gnorm:7.3f}  {tok_s:9.0f} tok/s")
+            history.append({"step": step, "loss": loss,
+                            "grad_norm": gnorm})
         if job.ckpt_dir and step and step % job.ckpt_every == 0:
             ckpt.save(f"{job.ckpt_dir}/step_{step}", params, step=step)
     if job.ckpt_dir:
@@ -1126,4 +1132,6 @@ def _run_spmd(job: SpmdTrainJob) -> dict:
                   step=job.steps)
     return {"history": history,
             "final_loss": history[-1]["loss"] if history else None,
-            "params": api.param_count(params)}
+            "params": api.param_count(params),
+            "param_devices": len({d for s in jax.tree.leaves(pshard)
+                                  for d in s.device_set})}

@@ -99,8 +99,9 @@ class Unit:
 
 
 # compiled shard programs shared across ModelExecs with identical
-# (cfg, optimizer, shard-range) — model-selection jobs train many clones of
-# one architecture, and recompiling per clone dominated benchmark wall time
+# (cfg, shard-range), and steps across identical (cfg, optimizer) —
+# model-selection jobs train many clones of one architecture, and
+# recompiling per clone dominated benchmark wall time
 _FN_CACHE: dict = {}
 
 
@@ -129,7 +130,9 @@ class ShardFunctions:
 
     def fwd(self, shard: Shard):
         if shard.index not in self._fwd:
-            key = (self.cfg, self.opt_cfg, shard.seg_lo, shard.seg_hi,
+            # forward/backward never read the optimizer config, so clones
+            # that differ only in lr share them; only the step is per-opt
+            key = (self.cfg, shard.seg_lo, shard.seg_hi,
                    "fwd", shard.index == len(self.partition.shards) - 1)
             if key not in _FN_CACHE:
                 _FN_CACHE[key] = jax.jit(partial(self._fwd_impl, shard))
@@ -146,8 +149,7 @@ class ShardFunctions:
     def bwd(self, shard: Shard):
         if shard.index not in self._bwd:
             last = shard.index == len(self.partition.shards) - 1
-            key = (self.cfg, self.opt_cfg, shard.seg_lo, shard.seg_hi,
-                   "bwd", last)
+            key = (self.cfg, shard.seg_lo, shard.seg_hi, "bwd", last)
             if key not in _FN_CACHE:
                 _FN_CACHE[key] = jax.jit(partial(
                     self._bwd_last_impl if last else self._bwd_impl, shard))

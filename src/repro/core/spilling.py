@@ -32,6 +32,19 @@ def to_host(tree):
     return jax.tree.map(lambda a: np.array(a), tree)
 
 
+def move_to_host(tree):
+    """`to_host` for device arrays that nothing else holds (fresh results):
+    each leaf is deleted on the device once copied.  On an accelerator a
+    fetched Array also caches its own host copy, so fetching a whole shard
+    with `to_host` briefly holds two host copies of it."""
+    def move(a):
+        host = np.array(a)
+        if isinstance(a, jax.Array):
+            a.delete()
+        return host
+    return jax.tree.map(move, tree)
+
+
 def to_device(tree, device=None):
     if device is None:
         return jax.tree.map(jnp.asarray, tree)
@@ -71,9 +84,10 @@ class HostModelStore:
         self.opt: dict[int, Any] = {}
         for shard in partition.shards:
             own = self._own_params(shard)
-            self.opt[shard.index] = to_host(opt.init_state(opt_cfg, own))
+            self.opt[shard.index] = move_to_host(
+                opt.init_state(opt_cfg, own))
         self.shared_opt = {
-            name: to_host(opt.init_state(
+            name: move_to_host(opt.init_state(
                 opt_cfg, sg.resolve_ref(self.params, ref)))
             for name, ref in plan.shared_refs.items()}
         # accumulated grads for shared params within the current mini-batch
@@ -103,12 +117,16 @@ class HostModelStore:
         return own, shared
 
     def demote_shard(self, shard: Shard, own, opt_state):
-        """Device -> host: write back possibly-updated params + opt state."""
+        """Device -> host: write back possibly-updated params + opt state.
+        Consumes ``own`` and ``opt_state``: their device arrays are freed."""
         for k, i in enumerate(range(shard.seg_lo, shard.seg_hi)):
             ref = self.plan.segments[i].param_ref
             if ref is not None and own[k] is not None:
-                sg.update_with_ref(self.params, ref, to_host(own[k]))
-        self.opt[shard.index] = to_host(opt_state)
+                sg.update_with_ref(self.params, ref, move_to_host(own[k]))
+        # the host copy is stale once promoted; drop it before fetching the
+        # new one so host DRAM never holds both
+        self.opt[shard.index] = None
+        self.opt[shard.index] = move_to_host(opt_state)
 
     def shard_shared_names(self, shard: Shard) -> list[str]:
         names: list[str] = []
@@ -138,8 +156,8 @@ class HostModelStore:
             p = to_device(sg.resolve_ref(self.params, ref))
             s = to_device(self.shared_opt[name])
             new_p, new_s = opt.update(self.opt_cfg, p, to_device(g), s)
-            sg.update_with_ref(self.params, ref, to_host(new_p))
-            self.shared_opt[name] = to_host(new_s)
+            sg.update_with_ref(self.params, ref, move_to_host(new_p))
+            self.shared_opt[name] = move_to_host(new_s)
         self.shared_grad_acc = {}
 
     # -- sizes --------------------------------------------------------------

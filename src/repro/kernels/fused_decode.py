@@ -8,11 +8,11 @@ its residual.  QKV projection, rope, and the KV row scatter stay
 outside — they write the pages the kernel reads.
 
 Grid is ``(lane, logical_block)`` with the block dimension innermost;
-the online-softmax scratch ``(m, l, acc)`` spans all ``n_heads`` rows
-and carries across blocks exactly like `paged_attention_lanes`.  At the
-last block the epilogue runs once per lane with every weight matrix
-resident in VMEM (constant BlockSpec index maps — sized for decode
-configs, where d and ffn fit comfortably).
+the attention step is `paged_attention.attend_block`, so the online-softmax
+scratch carries across blocks exactly as in `paged_attention_lanes`.  At
+the last block the epilogue runs once per lane with every weight matrix
+resident in VMEM (constant index maps, single-buffered, since they never
+change block), so the kernel's VMEM limit is raised to hold them.
 """
 
 from __future__ import annotations
@@ -25,68 +25,49 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels.paged_attention import (attend_block,
+                                           init_softmax_scratch)
+
+_VMEM_HEADROOM = 16 << 20   # pages, activations and Mosaic's own scratch
 
 
 def _fused_kernel(tables_ref, lengths_ref, h_ref, q_ref, k_ref, v_ref,
                   wo_ref, scale_ref, wg_ref, wu_ref, wd_ref, o_ref,
                   m_scr, l_scr, acc_scr, *,
-                  scale: float, block_size: int, n_kv_heads: int,
-                  window, eps: float):
+                  scale: float, groups: int, window, eps: float):
     lane = pl.program_id(0)
     b = pl.program_id(1)
-    nb = pl.num_programs(1)
 
     @pl.when(b == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        init_softmax_scratch(m_scr, l_scr, acc_scr)
 
-    nh, hd = q_ref.shape[1], q_ref.shape[2]
-    nkv = n_kv_heads
-    groups = nh // nkv
-    q = q_ref[0].astype(jnp.float32).reshape(nkv, groups, hd)
-    k = jnp.transpose(k_ref[0].astype(jnp.float32), (1, 0, 2))  # (nkv,bs,hd)
-    v = jnp.transpose(v_ref[0].astype(jnp.float32), (1, 0, 2))
+    attend_block(q_ref[0].astype(jnp.float32), k_ref, v_ref,
+                 lengths_ref[lane] - 1, b, m_scr, l_scr, acc_scr,
+                 scale=scale, groups=groups, window=window)
 
-    s = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,)))) * scale       # (nkv, groups, bs)
-    s = s.reshape(nh, block_size)
-
-    length = lengths_ref[lane]                   # valid rows incl. this token
-    k_pos = b * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (nh, block_size), 1)
-    mask = k_pos < length
-    if window is not None:
-        mask &= k_pos > (length - 1) - window
-    s = jnp.where(mask, s, NEG_INF)
-
-    m_prev = m_scr[...]
-    l_prev = l_scr[...]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_cur[:, None])
-    alpha = jnp.exp(m_prev - m_cur)
-    l_scr[...] = l_prev * alpha + jnp.sum(p, axis=-1)
-    pv = jax.lax.dot_general(
-        p.reshape(nkv, groups, block_size), v,
-        (((2,), (1,)), ((0,), (0,))))                     # (nkv, groups, hd)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + pv.reshape(nh, hd)
-    m_scr[...] = m_cur
-
-    @pl.when(b == nb - 1)
+    @pl.when(b == pl.num_programs(1) - 1)
     def _epilogue():
-        denom = jnp.maximum(l_scr[...], 1e-30)[:, None]
-        attn = (acc_scr[...] / denom).reshape(1, nh * hd)
-        h1 = h_ref[...].astype(jnp.float32) \
-            + attn @ wo_ref[...].astype(jnp.float32)
+        nkv, _, hd = acc_scr.shape
+        h1 = h_ref[0].astype(jnp.float32)                     # (1, d)
+        # attention output of head (kv, g) times its rows of wo
+        for kv in range(nkv):
+            for g in range(groups):
+                head = (acc_scr[kv, g:g + 1, :]
+                        / jnp.maximum(l_scr[kv, g:g + 1, :], 1e-30))
+                row = (kv * groups + g) * hd
+                h1 = h1 + _mm(head, wo_ref[row:row + hd, :])
         var = jnp.mean(jnp.square(h1), axis=-1, keepdims=True)
         hn = h1 * jax.lax.rsqrt(var + eps) \
             * scale_ref[...].astype(jnp.float32)
-        g = hn @ wg_ref[...].astype(jnp.float32)
-        u = hn @ wu_ref[...].astype(jnp.float32)
-        out = h1 + (jax.nn.silu(g) * u) @ wd_ref[...].astype(jnp.float32)
-        o_ref[...] = out.astype(o_ref.dtype)
+        act = jax.nn.silu(_mm(hn, wg_ref[...])) * _mm(hn, wu_ref[...])
+        o_ref[0] = (h1 + _mm(act, wd_ref[...])).astype(o_ref.dtype)
+
+
+def _mm(x, w):
+    """Activation times a weight block in the weight's dtype, f32 result:
+    no f32 copy of a weight matrix ever exists in VMEM."""
+    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
 
 
 def fused_decode_layer(h, q, k_pages, v_pages, tables, lengths, wo,
@@ -103,39 +84,45 @@ def fused_decode_layer(h, q, k_pages, v_pages, tables, lengths, wo,
     _, block_size, nkv, _ = k_pages.shape
     n_blocks = tables.shape[1]
     d = h.shape[1]
-    f = w_gate.shape[1]
     assert nh % nkv == 0
+    groups = nh // nkv
 
     kernel = functools.partial(_fused_kernel, scale=1.0 / math.sqrt(hd),
-                               block_size=block_size, n_kv_heads=nkv,
-                               window=window, eps=eps)
+                               groups=groups, window=window, eps=eps)
 
+    def weight_spec(shape):
+        return pl.BlockSpec(shape, lambda i, b, t, le: (0,) * len(shape),
+                            pipeline_mode=pl.Buffered(1))
+
+    weights = (wo, mlp_scale.reshape(1, d), w_gate, w_up, w_down)
+    weight_bytes = sum(w.size * w.dtype.itemsize for w in weights)
+    row_spec = pl.BlockSpec((1, 1, d), lambda i, b, t, le: (i, 0, 0))
     page_spec = pl.BlockSpec((1, block_size, nkv, hd),
                              lambda i, b, t, le: (t[i, b], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                   # tables, lengths
         grid=(n, n_blocks),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, b, t, le: (i, 0)),
-            pl.BlockSpec((1, nh, hd), lambda i, b, t, le: (i, 0, 0)),
+            row_spec,
+            pl.BlockSpec((1, nkv, groups, hd),
+                         lambda i, b, t, le: (i, 0, 0, 0)),
             page_spec, page_spec,
-            pl.BlockSpec((nh * hd, d), lambda i, b, t, le: (0, 0)),
-            pl.BlockSpec((d,), lambda i, b, t, le: (0,)),
-            pl.BlockSpec((d, f), lambda i, b, t, le: (0, 0)),
-            pl.BlockSpec((d, f), lambda i, b, t, le: (0, 0)),
-            pl.BlockSpec((f, d), lambda i, b, t, le: (0, 0)),
+            *(weight_spec(w.shape) for w in weights),
         ],
-        out_specs=pl.BlockSpec((1, d), lambda i, b, t, le: (i, 0)),
+        out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((nh,), jnp.float32),          # running max m
-            pltpu.VMEM((nh,), jnp.float32),          # running denom l
-            pltpu.VMEM((nh, hd), jnp.float32),       # attention accumulator
+            pltpu.VMEM((nkv, groups, 1), jnp.float32),    # running max m
+            pltpu.VMEM((nkv, groups, 1), jnp.float32),    # running denom l
+            pltpu.VMEM((nkv, groups, hd), jnp.float32),   # attention acc
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, d), h.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, 1, d), h.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=weight_bytes + _VMEM_HEADROOM),
         interpret=interpret,
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), h, q,
-      k_pages, v_pages, wo, mlp_scale, w_gate, w_up, w_down)
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), h[:, None],
+      q.reshape(n, nkv, groups, hd), k_pages, v_pages, *weights)
+    return out[:, 0]

@@ -17,8 +17,8 @@ from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.fused_decode import fused_decode_layer as _fused_layer
 from repro.kernels.paged_attention import (paged_attention_lanes,
-                                           paged_attention_quant_lanes)
-from repro.kernels.paged_verify import paged_verify_lanes
+                                           paged_attention_quant_lanes,
+                                           paged_verify_lanes)
 from repro.kernels.rmsnorm import rms_norm_2d
 from repro.kernels.ssd_scan import ssd_scan_bshpn
 from repro.kernels.swiglu import swiglu_2d

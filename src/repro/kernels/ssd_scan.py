@@ -30,29 +30,38 @@ def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, o_ref, state_scr, *,
         state_scr[...] = jnp.zeros_like(state_scr)
 
     x = x_ref[0].astype(jnp.float32)             # (Q, p)
-    a = a_ref[0].astype(jnp.float32)             # (Q,)
+    a = a_ref[0].astype(jnp.float32)             # (1, Q)
     b = b_ref[0].astype(jnp.float32)             # (Q, n)
     c = c_ref[0].astype(jnp.float32)             # (Q, n)
+    n = b.shape[1]
 
-    a_cum = jnp.cumsum(a)                        # (Q,)
-    a_tot = a_cum[-1]
-
-    # intra-chunk (quadratic in Q)
-    li = a_cum[:, None] - a_cum[None, :]
+    # prefix sums as matmuls with triangular / all-ones matrices (Mosaic has
+    # neither cumsum nor a scalar broadcast out of a vector); HIGHEST keeps
+    # the log-decay sums at f32 accuracy
     row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    li = jnp.where(row >= col, li, -1e30)        # mask BEFORE exp
+
+    def sums(m):
+        return jnp.dot(a, m.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+
+    a_row = sums(row <= col)                     # (1, Q) inclusive cumsum
+    a_col = a_row.T                              # (Q, 1)
+    a_tot = sums(jnp.ones((chunk, n), bool))     # (1, n) chunk total
+
+    # intra-chunk (quadratic in Q)
+    li = jnp.where(row >= col, a_col - a_row, -1e30)   # mask BEFORE exp
     decay = jnp.exp(li)
     scores = (c @ b.T) * decay                   # (Q, Q)
     y = scores @ x                               # (Q, p)
 
     # inter-chunk contribution from the carried state
     state = state_scr[...]                       # (p, n)
-    y = y + jnp.exp(a_cum)[:, None] * (c @ state.T)
+    y = y + jnp.exp(a_col) * (c @ state.T)
 
-    # state update for the next chunk
-    w = jnp.exp(a_tot - a_cum)                   # (Q,)
-    state_scr[...] = jnp.exp(a_tot) * state + (x * w[:, None]).T @ b
+    # state update for the next chunk: row i decays by the sum after it
+    w = jnp.exp(sums(row > col)).T               # (Q, 1)
+    state_scr[...] = jnp.exp(a_tot) * state + (x * w).T @ b
 
     o_ref[0] = y.astype(o_ref.dtype)
 
@@ -74,7 +83,7 @@ def ssd_scan_bshpn(x, log_a, b_coef, c_coef, *, chunk: int,
     ct = c_coef.transpose(0, 2, 1, 3).reshape(bsz, h, nc, chunk, n)
     # fold (b, h) since the grid treats them identically
     xt = xt.reshape(bsz * h, nc, chunk, p)
-    at = at.reshape(bsz * h, nc, chunk)
+    at = at.reshape(bsz * h, nc, 1, chunk)   # (1, chunk) blocks: tileable
     bt = bt.reshape(bsz * h, nc, chunk, n)
     ct = ct.reshape(bsz * h, nc, chunk, n)
 
@@ -84,7 +93,8 @@ def ssd_scan_bshpn(x, log_a, b_coef, c_coef, *, chunk: int,
         grid=(bsz * h, nc),
         in_specs=[
             pl.BlockSpec((1, None, chunk, p), lambda bh, ci: (bh, ci, 0, 0)),
-            pl.BlockSpec((1, None, chunk), lambda bh, ci: (bh, ci, 0)),
+            pl.BlockSpec((1, None, 1, chunk),
+                         lambda bh, ci: (bh, ci, 0, 0)),
             pl.BlockSpec((1, None, chunk, n), lambda bh, ci: (bh, ci, 0, 0)),
             pl.BlockSpec((1, None, chunk, n), lambda bh, ci: (bh, ci, 0, 0)),
         ],

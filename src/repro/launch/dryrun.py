@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: prove every (architecture × input shape × mesh)
 combination lowers, partitions, and fits — with zero real allocation.
 
@@ -18,6 +15,11 @@ Usage:
   python -m repro.launch.dryrun --all --out results/dryrun.jsonl
   python -m repro.launch.dryrun --all --multi-pod
 """
+
+import os
+# 512 virtual CPU devices stand in for the pod; never the attached chip
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 import argparse
 import json

@@ -11,16 +11,11 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh(shape, axes):
-    """``jax.make_mesh`` with explicit Auto axis types where the installed
-    jax supports them (``jax.sharding.AxisType`` landed after 0.4.x; on
-    older versions every axis is already Auto, so plain make_mesh is
-    equivalent)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis Auto (sharding propagated by the
+    compiler), over ``devices`` when given."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

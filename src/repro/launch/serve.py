@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from repro.api import ServeJob, Session
 from repro.configs import get_config
 from repro.core.sharp import HydraConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build_serve_job(arch: str, args) -> ServeJob:
@@ -136,6 +137,7 @@ def serve_http(args):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True,
                     help="model id, or comma-separated list for multi-model")

@@ -19,6 +19,7 @@ import json
 
 from repro.api import Session, SpmdTrainJob
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def job_from_args(args) -> SpmdTrainJob:
@@ -39,6 +40,7 @@ def train(args) -> dict:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",

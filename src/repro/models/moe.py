@@ -191,19 +191,14 @@ def _moe_mlp_shardmap(params, x, cfg, mesh):
         gathered = jnp.where(keep_b[..., None], gathered, 0)
         return jnp.sum(gathered * gates_b[..., None].astype(dt), axis=2)
 
-    if hasattr(jax, "shard_map"):
-        shard_map, check_kw = jax.shard_map, {"check_vma": False}
-    else:   # pre-0.5 jax: experimental home, and the flag is check_rep
-        from jax.experimental.shard_map import shard_map
-        check_kw = {"check_rep": False}
-    y = shard_map(
+    y = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(B, None, None), P(B, None, None), P(B, None, None),
                   P(B, None, None), P(B, None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
         out_specs=P(B, None, None),
-        **check_kw,
+        check_vma=False,
     )(x, gate_vals, expert_idx, pos_in_expert, keep,
       params["w_gate"], params["w_up"], params["w_down"])
     return y, aux

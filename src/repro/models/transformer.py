@@ -225,7 +225,7 @@ def paged_verify_step(cfg, params, pages, tables, lengths, tokens, *,
     rows past a lane's length are masked to zero weight, so rejected
     draft rows never perturb later decode.  ``impl`` routes the per-lane
     attention: 'jnp' is the historical gathered path, 'pallas' the Mosaic
-    multi-query kernel (`kernels/paged_verify.py`)."""
+    multi-query kernel (`kernels/paged_attention.py`)."""
     x = nn.embed(params["embed"], tokens, cfg.dtype)
 
     def body(h, xs):

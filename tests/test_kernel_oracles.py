@@ -13,10 +13,9 @@ correct reference.  This harness is the gate:
   honor — lengths on a block boundary, garbage-block / stale-row
   invisibility (poisoned pages change nothing), and single-token lanes;
 * the int8 KV path gets round-trip properties (zero rows exact, error
-  bounded by half a quantization step) plus step-level decode
-  token-identity vs the fp pool for both paged families (dense, vlm),
-  with the max-logit drift REPORTED, not asserted — precision loss is
-  a measured quantity here, only token flips are failures.
+  bounded by half a quantization step) plus teacher-forced decode
+  logits against the fp pool for both paged families (dense, vlm),
+  within a stated bf16 bound, with the max logit drift reported.
 
 All Pallas launches run in interpret mode so the harness is hermetic on
 CPU hosts; on TPU the same entry points compile for real.
@@ -344,44 +343,56 @@ def test_quant_zero_rows_exact():
     np.testing.assert_array_equal(np.asarray(ref.dequantize_kv(q, s)), 0.0)
 
 
-def _paged_family_tokens(cfg, params, kv_dtype, steps=12, seed=5):
-    """Greedy token ids + per-step max logits from paged decode steps,
-    growing the pool from empty (every step scatters then attends)."""
+# Teacher-forced decode comparisons: both paths are fed the same seeded
+# tokens, so one rounding difference cannot change what later steps see,
+# and logits are compared under a bound instead of argmax (on random
+# weights the top two logits sit ~1e-2 apart, so argmax flips on rounding).
+# The smoke stacks compute in bf16, whose step at the ~1.3 logit peak is
+# 2**-7 ~ 8e-3; 5e-2 allows ~6 such steps accumulated through the layers.
+DECODE_LOGIT_TOL = 5e-2
+
+
+def _paged_family_logits(cfg, params, kv_dtype, impl="jnp", steps=12,
+                         seed=5):
+    """Last-position logits per step, (steps, n, vocab), from teacher-forced
+    paged decode steps growing the pool from empty (every step scatters
+    then attends)."""
     from repro.models import api
-    n, bs, B = 2, 4, (steps + 1 + 3) // 4 + 1
+    n, bs, B = 2, 4, (steps + 3) // 4 + 1
     P = n * B + 1
     pages = api.init_kv_pages(cfg, P, bs, kv_dtype)
     rng = np.random.default_rng(seed)
     tables = jnp.asarray(
         (rng.permutation(P - 1)[: n * B] + 1).reshape(n, B), jnp.int32)
-    tok = jnp.asarray(rng.integers(0, cfg.vocab_size, (n, 1)), jnp.int32)
-    toks, logit_peaks = [], []
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (steps, n, 1)),
+                         jnp.int32)
+    out = []
     for step in range(steps):
         lengths = jnp.full((n,), step, jnp.int32)
         logits, pages = api.paged_decode_step(
-            cfg, params, pages, tables, lengths, tok, impl="jnp")
-        tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
-        toks.append(np.asarray(tok)[:, 0].copy())
-        logit_peaks.append(np.asarray(logits[:, -1], np.float32))
-    return np.stack(toks), np.stack(logit_peaks)
+            cfg, params, pages, tables, lengths, tokens[step], impl=impl)
+        out.append(np.asarray(logits[:, -1], np.float32))
+    return np.stack(out)
 
 
 @pytest.mark.parametrize("model", ["qwen3-0.6b", "llava-next-mistral-7b"])
 def test_int8_kv_decode_token_identity(model):
-    """int8 KV pages decode token-identically to the fp pool on a seeded
-    suite, for every kv_quant family (dense, vlm).  The max logit delta
-    is reported — drift is a measured quantity, token flips are bugs."""
+    """int8 KV pages decode like the fp pool on the same tokens, for every
+    kv_quant family (dense, vlm): logits agree within DECODE_LOGIT_TOL.
+    Per-row int8 rounding is at most amax/254 per element, about one bf16
+    step, so the quantized pool adds no more than the stack's own bf16
+    rounding.  The max logit delta is reported."""
     from repro.configs import get_config
     from repro.models import api
     cfg = get_config(model, smoke=True)
     params = api.init_params(cfg, jax.random.PRNGKey(0))
-    fp_toks, fp_logits = _paged_family_tokens(cfg, params, None)
-    q_toks, q_logits = _paged_family_tokens(cfg, params, "int8")
-    drift = float(np.max(np.abs(fp_logits - q_logits)))
-    rel = drift / (float(np.max(np.abs(fp_logits))) + 1e-9)
+    fp = _paged_family_logits(cfg, params, None)
+    q = _paged_family_logits(cfg, params, "int8")
+    drift = float(np.max(np.abs(fp - q)))
     print(f"\n[kv-quant drift] {model}: max |logit delta| = {drift:.4f} "
-          f"({rel:.2%} of peak logit) over {fp_toks.shape[0]} steps")
-    np.testing.assert_array_equal(fp_toks, q_toks)
+          f"over {fp.shape[0]} steps")
+    np.testing.assert_allclose(q, fp, rtol=DECODE_LOGIT_TOL,
+                               atol=DECODE_LOGIT_TOL)
 
 
 def test_int8_kv_default_stays_fp():
@@ -415,31 +426,16 @@ def test_int8_kv_rejects_non_quant_family():
 
 def test_fused_impl_matches_jnp_paged_decode():
     """impl='fused_interpret' (fused layer kernel per scan step) is
-    numerically interchangeable with the jnp paged decode path, and
-    token-identical on the argmax."""
+    numerically interchangeable with the jnp paged decode path: on the
+    same tokens, logits agree within DECODE_LOGIT_TOL (the fused kernel
+    keeps the residual and the norm in f32 where the jnp path rounds them
+    to bf16)."""
     from repro.configs import get_config
     from repro.models import api
     cfg = get_config("qwen3-0.6b", smoke=True)
     params = api.init_params(cfg, jax.random.PRNGKey(0))
-    n, bs, B = 2, 4, 5
-    P = n * B + 1
-    rng = np.random.default_rng(3)
-    tables = jnp.asarray(
-        (rng.permutation(P - 1)[: n * B] + 1).reshape(n, B), jnp.int32)
-    pages_j = api.init_kv_pages(cfg, P, bs)
-    pages_f = api.init_kv_pages(cfg, P, bs)
-    tok_j = tok_f = jnp.asarray(rng.integers(0, cfg.vocab_size, (n, 1)),
-                                jnp.int32)
-    for step in range(6):
-        lengths = jnp.full((n,), step, jnp.int32)
-        lj, pages_j = api.paged_decode_step(
-            cfg, params, pages_j, tables, lengths, tok_j, impl="jnp")
-        lf, pages_f = api.paged_decode_step(
-            cfg, params, pages_f, tables, lengths, tok_f,
-            impl="fused_interpret")
-        np.testing.assert_allclose(
-            np.asarray(lj, np.float32), np.asarray(lf, np.float32),
-            rtol=5e-2, atol=5e-2)      # bf16 end-to-end stack rounding
-        tok_j = jnp.argmax(lj[:, -1], -1).astype(jnp.int32)[:, None]
-        tok_f = jnp.argmax(lf[:, -1], -1).astype(jnp.int32)[:, None]
-        np.testing.assert_array_equal(np.asarray(tok_j), np.asarray(tok_f))
+    jnp_logits = _paged_family_logits(cfg, params, None, steps=6, seed=3)
+    fused = _paged_family_logits(cfg, params, None, impl="fused_interpret",
+                                 steps=6, seed=3)
+    np.testing.assert_allclose(fused, jnp_logits, rtol=DECODE_LOGIT_TOL,
+                               atol=DECODE_LOGIT_TOL)

@@ -53,7 +53,7 @@ print("E2E_OK", txt.count("all-to-all"))
 
 
 def test_shardmap_moe_subprocess():
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", CODE], capture_output=True,
                          text=True, env=env, timeout=900)
     assert out.returncode == 0, out.stderr[-3000:]

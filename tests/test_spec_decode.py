@@ -331,7 +331,7 @@ def test_session_spec_job_end_to_end(dense):
 
 
 # ---------------------------------------------------------------------------
-# fused multi-query paged-verify kernel (kernels/paged_verify.py)
+# fused multi-query paged-verify kernel (kernels/paged_attention.py)
 # ---------------------------------------------------------------------------
 
 @settings(max_examples=6, deadline=None)

@@ -37,7 +37,7 @@ def test_paper_fig4_api():
 
 
 def _run_subprocess(code: str) -> str:
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, env=env, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
