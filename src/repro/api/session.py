@@ -33,6 +33,7 @@ from typing import Any, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.api.jobs import (EvalJob, JobSpec, ServeJob, SpmdTrainJob,
                             TrainJob)
@@ -886,6 +887,29 @@ class Session:
     def _run_impl(self, plan: Optional[Plan],
                   max_units: Optional[int]) -> SessionReport:
         wall0 = time.perf_counter()
+        # the profiler spans of the run's own host work; the executor's
+        # spans fall between them, so no span encloses another
+        ids = self._span_ids()
+        with TraceAnnotation("session.prepare", **ids):
+            train_ids, executor = self._prepare_run(plan)
+        report = SessionReport()
+        if executor is not None:
+            report.train = executor.run(max_units=max_units,
+                                        on_unit=self._on_unit)
+        with TraceAnnotation("session.finish", **ids):
+            return self._finish_run(report, train_ids, wall0)
+
+    def _span_ids(self) -> dict:
+        """What a run's ``session.*`` spans carry: the lowest step of the
+        train models built so far, and their ids joined by ``+`` (-1 and
+        empty before the first run builds them)."""
+        execs = sorted(self._train_execs.values(), key=lambda m: m.model_id)
+        return {"step": min((m.minibatch for m in execs), default=-1),
+                "model": "+".join(str(m.model_id) for m in execs)}
+
+    def _prepare_run(self, plan: Optional[Plan]):
+        """Materialize, mark the train jobs running, reset train residency
+        and build the executor (None without train work)."""
         # under the engine lock: a concurrent submit_request during an
         # async run materializes lazily via engine(), and two builders for
         # one job would double-init params and clobber cold-serve state
@@ -898,7 +922,6 @@ class Session:
                 self._verify_plan_config(plan)   # before any state is built
                 self._materialize(plan)
                 self._verify_plan_partitions(plan)
-        report = SessionReport()
 
         train_ids = [jid for jid in self._active(TrainJob)
                      if jid in self._train_execs]
@@ -906,19 +929,23 @@ class Session:
                        key=lambda m: m.model_id)
         for jid in train_ids:
             self._state[jid] = JobState.RUNNING
+        if not execs:
+            return train_ids, None
+        # train residency is rebuilt from the host stores each run;
+        # live KV-page reservations (in-flight serve requests) persist
+        for dm in self.devices:
+            dm.resident_bytes = 0
+            dm.buffered_bytes = 0
+        return train_ids, SharpExecutor(self.hc, execs, devices=self.devices)
 
-        def on_unit(ev: UnitEvent):
-            self.unit_trace.append(ev.key())
-            self.serve_tick()        # serve jobs tick between shard units
+    def _on_unit(self, ev: UnitEvent) -> None:
+        self.unit_trace.append(ev.key())
+        self.serve_tick()        # serve jobs tick between shard units
 
-        if execs:
-            # train residency is rebuilt from the host stores each run;
-            # live KV-page reservations (in-flight serve requests) persist
-            for dm in self.devices:
-                dm.resident_bytes = 0
-                dm.buffered_bytes = 0
-            executor = SharpExecutor(self.hc, execs, devices=self.devices)
-            report.train = executor.run(max_units=max_units, on_unit=on_unit)
+    def _finish_run(self, report: SessionReport, train_ids: list,
+                    wall0: float) -> SessionReport:
+        """Settle the run's train jobs, run its spmd and eval jobs, drain
+        serving and complete ``report``."""
         for jid in train_ids:
             # don't stomp a mid-run cancel, and a max_units-truncated job
             # goes back to pending (its exec state persists; run() resumes)

@@ -27,6 +27,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import scheduler as sched
 from repro.core import shard_graph as sg
@@ -213,7 +214,10 @@ class ModelExec:
                   for s in reversed(shards)]
         self.queue = units
         self.cursor = 0
-        self.current_batch = jax.tree.map(jnp.asarray, next(self.data_iter))
+        with TraceAnnotation("sharp.batch", step=self.minibatch,
+                             model=self.model_id):
+            self.current_batch = jax.tree.map(jnp.asarray,
+                                              next(self.data_iter))
 
     def next_unit(self) -> Optional[Unit]:
         if self.done:
@@ -261,6 +265,14 @@ class UnitEvent:
 
 @dataclass
 class RunReport:
+    """What ``SharpExecutor.run`` did.  ``makespan``, ``utilization``,
+    ``avg_utilization`` and the exposed/hidden transfer times come from
+    the executor's virtual clock (measured pilot runtimes, or
+    ``fixed_unit_runtime``, and transfers priced at ``link_bw``): they are
+    modelled, not measured.  ``transfer`` counts the bytes really moved,
+    ``wall_time`` is the host clock.  The measured per-phase timeline is
+    the profiler trace of the run's host spans (docs/architecture.md,
+    "Tracing")."""
     makespan: float
     utilization: dict[int, float]
     avg_utilization: float
@@ -345,38 +357,50 @@ class SharpExecutor:
                 shard.est_runtime = shard.fwd_runtime + shard.bwd_runtime
 
     # -- real unit execution -------------------------------------------------
-    def _execute_unit(self, m: ModelExec, unit: Unit) -> None:
+    def _execute_unit(self, m: ModelExec, unit: Unit) -> int:
+        """Run one unit; returns the bytes it fetched device -> host.  Each
+        host phase is a profiler span (no span encloses another)."""
         shard = unit.shard
         batch = m.current_batch
-        own, shared, opt_state = m.store.promote_shard(shard)
+        ids = dict(step=m.minibatch, model=m.model_id, shard=shard.index)
+        with TraceAnnotation("spill.promote", dir=unit.direction, **ids):
+            own, shared, opt_state = m.store.promote_shard(shard)
+        fetched = 0
         if unit.direction == "fwd":
             act_in = {} if shard.index == 0 \
                 else m.saved_acts[("exit", shard.index - 1)]
             # entry activation is the checkpoint this shard's backward reuses
             m.saved_acts[("entry", shard.index)] = act_in
-            out, loss = m.fns.fwd(shard)(own, shared, act_in, batch)
+            with TraceAnnotation("sharp.dispatch", dir="fwd", **ids):
+                out, loss = m.fns.fwd(shard)(own, shared, act_in, batch)
             if shard.index == len(m.partition.shards) - 1:
-                m.losses.append(float(loss))
+                with TraceAnnotation("sharp.loss_read", dir="fwd", **ids):
+                    m.losses.append(float(loss))
             m.saved_acts[("exit", shard.index)] = out
         else:
             act_in = m.saved_acts[("entry", shard.index)]
             last = shard.index == len(m.partition.shards) - 1
-            if last:
-                loss, g_own, g_shared, g_act = m.fns.bwd(shard)(
-                    own, shared, act_in, batch)
-            else:
-                g_own, g_shared, g_act = m.fns.bwd(shard)(
-                    own, shared, act_in, m.saved_cot, batch)
+            with TraceAnnotation("sharp.dispatch", dir="bwd", **ids):
+                if last:
+                    loss, g_own, g_shared, g_act = m.fns.bwd(shard)(
+                        own, shared, act_in, batch)
+                else:
+                    g_own, g_shared, g_act = m.fns.bwd(shard)(
+                        own, shared, act_in, m.saved_cot, batch)
             m.saved_cot = g_act
             shared_names = m.store.shard_shared_names(shard)
             if shared_names:
-                m.store.accumulate_shared_grads(
-                    {n: g_shared.get(n) for n in shared_names})
-            new_own, new_opt = m.fns._step(own, g_own, opt_state)
-            m.store.demote_shard(shard, new_own, new_opt)
+                with TraceAnnotation("spill.shared_grads", dir="bwd", **ids):
+                    fetched += m.store.accumulate_shared_grads(
+                        {n: g_shared.get(n) for n in shared_names})
+            with TraceAnnotation("sharp.dispatch", dir="step", **ids):
+                new_own, new_opt = m.fns._step(own, g_own, opt_state)
+            with TraceAnnotation("spill.demote", dir="bwd", **ids):
+                fetched += m.store.demote_shard(shard, new_own, new_opt)
             # free this shard's saved activations
             m.saved_acts.pop(("entry", shard.index), None)
             m.saved_acts.pop(("exit", shard.index), None)
+        return fetched
 
     # -- event loop -----------------------------------------------------------
     def run(self, *, max_units: Optional[int] = None,
@@ -460,9 +484,9 @@ class SharpExecutor:
                 dev.charge_act(act_bytes)
 
             # ---- real compute --------------------------------------------
-            self._execute_unit(m, unit)
+            fetched = self._execute_unit(m, unit)
             self.units_executed += 1
-            dev.charge_demotion(shard_bytes)
+            dev.charge_demotion(shard_bytes, moved=fetched)
             if on_unit is not None:
                 on_unit(UnitEvent(
                     model_id=m.model_id, shard_index=unit.shard.index,
@@ -475,7 +499,7 @@ class SharpExecutor:
             m.reserved = False
             m.act_location = d
             if m.cursor >= len(m.queue):
-                self._finish_minibatch(m)
+                dev.charge_fetch(self._finish_minibatch(m))
             if not self.hc.enable_sharp and m.done and \
                     self.active_model == m.model_id:
                 self.active_model = None
@@ -510,8 +534,12 @@ class SharpExecutor:
             self.active_model = min(m.model_id for m in live)
         return [m for m in live if m.model_id == self.active_model]
 
-    def _finish_minibatch(self, m: ModelExec):
-        m.store.step_shared()
+    def _finish_minibatch(self, m: ModelExec) -> int:
+        """Step the shared params and advance ``m`` to its next mini-batch;
+        returns the bytes the shared step fetched device -> host."""
+        with TraceAnnotation("spill.shared_step", step=m.minibatch,
+                             model=m.model_id):
+            fetched = m.store.step_shared()
         m.saved_acts.clear()
         m.saved_cot = None
         m.act_location = None
@@ -530,5 +558,6 @@ class SharpExecutor:
         if m.done:
             if not self.hc.enable_sharp and self.active_model == m.model_id:
                 self.active_model = None
-            return
+            return fetched
         m.build_minibatch_queue()
+        return fetched

@@ -116,17 +116,22 @@ class HostModelStore:
                   for n in self.shard_shared_names(shard)}
         return own, shared
 
-    def demote_shard(self, shard: Shard, own, opt_state):
+    def demote_shard(self, shard: Shard, own, opt_state) -> int:
         """Device -> host: write back possibly-updated params + opt state.
-        Consumes ``own`` and ``opt_state``: their device arrays are freed."""
+        Consumes ``own`` and ``opt_state``: their device arrays are freed.
+        Returns the bytes fetched."""
+        fetched = 0
         for k, i in enumerate(range(shard.seg_lo, shard.seg_hi)):
             ref = self.plan.segments[i].param_ref
             if ref is not None and own[k] is not None:
-                sg.update_with_ref(self.params, ref, move_to_host(own[k]))
+                host = move_to_host(own[k])
+                fetched += tree_bytes(host)
+                sg.update_with_ref(self.params, ref, host)
         # the host copy is stale once promoted; drop it before fetching the
         # new one so host DRAM never holds both
         self.opt[shard.index] = None
         self.opt[shard.index] = move_to_host(opt_state)
+        return fetched + tree_bytes(self.opt[shard.index])
 
     def shard_shared_names(self, shard: Shard) -> list[str]:
         names: list[str] = []
@@ -137,28 +142,38 @@ class HostModelStore:
         return names
 
     # -- shared ------------------------------------------------------------
-    def accumulate_shared_grads(self, grads: dict[str, Any]):
+    def accumulate_shared_grads(self, grads: dict[str, Any]) -> int:
+        """Fetch shared-param grads and add them on the host; returns the
+        bytes fetched."""
+        fetched = 0
         for name, g in grads.items():
             if g is None:
                 continue
+            fetched += tree_bytes(g)
             if name in self.shared_grad_acc:
                 self.shared_grad_acc[name] = jax.tree.map(
                     lambda a, b: a + np.asarray(b),
                     self.shared_grad_acc[name], g)
             else:
                 self.shared_grad_acc[name] = to_host(g)
+        return fetched
 
-    def step_shared(self):
-        """Apply accumulated shared-param grads (mini-batch boundary)."""
+    def step_shared(self) -> int:
+        """Apply accumulated shared-param grads (mini-batch boundary);
+        returns the bytes of new params and moments fetched back."""
         from repro.optim import optimizers as opt
+        fetched = 0
         for name, g in self.shared_grad_acc.items():
             ref = self.plan.shared_refs[name]
             p = to_device(sg.resolve_ref(self.params, ref))
             s = to_device(self.shared_opt[name])
             new_p, new_s = opt.update(self.opt_cfg, p, to_device(g), s)
-            sg.update_with_ref(self.params, ref, move_to_host(new_p))
+            new_p = move_to_host(new_p)
+            sg.update_with_ref(self.params, ref, new_p)
             self.shared_opt[name] = move_to_host(new_s)
+            fetched += tree_bytes(new_p) + tree_bytes(self.shared_opt[name])
         self.shared_grad_acc = {}
+        return fetched
 
     # -- sizes --------------------------------------------------------------
     def shard_transfer_bytes(self, shard: Shard, *, train: bool = True) -> int:
@@ -362,10 +377,18 @@ class DeviceMemory:
         self.resident_bytes += self.buffered_bytes
         self.buffered_bytes = 0
 
-    def charge_demotion(self, nbytes: int):
+    def charge_demotion(self, nbytes: int, *, moved: Optional[int] = None):
+        """Release ``nbytes`` of shard residency and book ``moved`` bytes
+        (all ``nbytes`` unless given) as device -> host traffic: a SHARP
+        forward unit gives its residency back and fetches nothing."""
         self.resident_bytes = max(0, self.resident_bytes - nbytes)
-        self.stats.demoted_bytes += nbytes
         self.stats.n_demotions += 1
+        self.charge_fetch(nbytes if moved is None else moved)
+
+    def charge_fetch(self, nbytes: int):
+        """Book device -> host bytes that release no residency (the shared
+        params' optimizer step)."""
+        self.stats.demoted_bytes += nbytes
 
     def charge_act(self, nbytes: int):
         self.stats.act_bytes_moved += nbytes
