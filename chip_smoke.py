@@ -183,6 +183,7 @@ def train_phase(dev, clock: CompileClock):
     info("train", arch=TRAIN_ARCH, params=n_params, budget_bytes=budget,
          shards=shards, promoted_bytes=stats.promoted_bytes,
          demoted_bytes=stats.demoted_bytes,
+         host_copied_bytes=stats.host_copied_bytes,
          units=report.train.units_executed, wall_s=round(wall, 3),
          peak_bytes_in_use=dev.memory_stats()["peak_bytes_in_use"],
          host_peak_rss_gib=round(host_peak_rss_gib(), 2),

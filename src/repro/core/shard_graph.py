@@ -75,27 +75,34 @@ def resolve_ref(params: ParamTree, ref: Optional[tuple]):
     return node
 
 
-def update_with_ref(params: ParamTree, ref: tuple, new_val) -> ParamTree:
-    """Write ``new_val`` back at ``ref`` into the host tree (in place)."""
+def update_with_ref(params: ParamTree, ref: tuple, new_val) -> int:
+    """Write ``new_val`` back at ``ref`` into the host tree (in place).  A
+    layer slice is copied into the stacked arrays; any other ref takes
+    ``new_val``'s arrays as they are.  Returns the bytes copied."""
     if ref is None:
-        return params
+        return 0
     if len(ref) == 4 and ref[0] == "stack_slice":
         _, key, lo, hi = ref
+        copied = 0
 
         def write(dst, src):
+            nonlocal copied
             dst = np.asarray(dst)
             if not dst.flags.writeable:
                 dst = dst.copy()
-            dst[lo:hi] = np.asarray(src)
+                copied += dst.nbytes
+            src = np.asarray(src)
+            dst[lo:hi] = src
+            copied += src.nbytes
             return dst
 
         params[key] = jax.tree.map(write, params[key], new_val)
-        return params
+        return copied
     node = params
     for k in ref[:-1]:
         node = node[k]
     node[ref[-1]] = jax.tree.map(np.asarray, new_val)
-    return params
+    return 0
 
 
 # ---------------------------------------------------------------------------

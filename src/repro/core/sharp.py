@@ -32,8 +32,7 @@ from jax.profiler import TraceAnnotation
 from repro.core import scheduler as sched
 from repro.core import shard_graph as sg
 from repro.core.partitioner import PartitionResult, Shard, tree_bytes
-from repro.core.spilling import (DeviceMemory, HostModelStore, to_device,
-                                 to_host)
+from repro.core.spilling import DeviceMemory, HostModelStore
 from repro.optim import optimizers as opt
 
 
@@ -484,6 +483,7 @@ class SharpExecutor:
                 dev.charge_act(act_bytes)
 
             # ---- real compute --------------------------------------------
+            copied = m.store.host_copied_bytes
             fetched = self._execute_unit(m, unit)
             self.units_executed += 1
             dev.charge_demotion(shard_bytes, moved=fetched)
@@ -500,6 +500,7 @@ class SharpExecutor:
             m.act_location = d
             if m.cursor >= len(m.queue):
                 dev.charge_fetch(self._finish_minibatch(m))
+            dev.stats.host_copied_bytes += m.store.host_copied_bytes - copied
             if not self.hc.enable_sharp and m.done and \
                     self.active_model == m.model_id:
                 self.active_model = None

@@ -33,16 +33,20 @@ def to_host(tree):
 
 
 def move_to_host(tree):
-    """`to_host` for device arrays that nothing else holds (fresh results):
-    each leaf is deleted on the device once copied.  On an accelerator a
-    fetched Array also caches its own host copy, so fetching a whole shard
-    with `to_host` briefly holds two host copies of it."""
-    def move(a):
-        host = np.array(a)
+    """Fetch device arrays that nothing else holds (fresh results) and
+    free them on the device.  Every leaf's copy starts before any is read,
+    so the transfers queue together, and each leaf comes back as the host
+    array its copy landed in: read-only, and never copied again."""
+    for a in jax.tree.leaves(tree):
+        if isinstance(a, jax.Array):
+            a.copy_to_host_async()
+
+    def take(a):
+        host = np.asarray(a)
         if isinstance(a, jax.Array):
             a.delete()
         return host
-    return jax.tree.map(move, tree)
+    return jax.tree.map(take, tree)
 
 
 def to_device(tree, device=None):
@@ -55,6 +59,9 @@ def to_device(tree, device=None):
 class TransferStats:
     promoted_bytes: int = 0
     demoted_bytes: int = 0
+    # bytes written on the host from fetched arrays (copies, slice writes
+    # into the stacked weights, the shared-gradient sum); not traffic
+    host_copied_bytes: int = 0
     n_promotions: int = 0
     n_demotions: int = 0
     act_bytes_moved: int = 0
@@ -80,6 +87,9 @@ class HostModelStore:
         self.plan = plan
         self.partition = partition
         self.params = sg.prepare_host_params(cfg, to_host(params))
+        # bytes written on the host from fetched arrays, building the store
+        # included; the executor books each unit's share in TransferStats
+        self.host_copied_bytes = tree_bytes(self.params)
         self.opt_cfg = opt_cfg
         self.opt: dict[int, Any] = {}
         for shard in partition.shards:
@@ -119,19 +129,21 @@ class HostModelStore:
     def demote_shard(self, shard: Shard, own, opt_state) -> int:
         """Device -> host: write back possibly-updated params + opt state.
         Consumes ``own`` and ``opt_state``: their device arrays are freed.
+        All of the unit's copies start before any is read; the moments are
+        kept as they land and the params copied into the stacked store.
         Returns the bytes fetched."""
-        fetched = 0
+        # the host moments are stale once promoted; drop them before the
+        # new ones are fetched so host DRAM never holds both
+        self.opt[shard.index] = None
+        own, self.opt[shard.index] = move_to_host((own, opt_state))
+        fetched = tree_bytes(self.opt[shard.index])
         for k, i in enumerate(range(shard.seg_lo, shard.seg_hi)):
             ref = self.plan.segments[i].param_ref
             if ref is not None and own[k] is not None:
-                host = move_to_host(own[k])
-                fetched += tree_bytes(host)
-                sg.update_with_ref(self.params, ref, host)
-        # the host copy is stale once promoted; drop it before fetching the
-        # new one so host DRAM never holds both
-        self.opt[shard.index] = None
-        self.opt[shard.index] = move_to_host(opt_state)
-        return fetched + tree_bytes(self.opt[shard.index])
+                fetched += tree_bytes(own[k])
+                self.host_copied_bytes += sg.update_with_ref(
+                    self.params, ref, own[k])
+        return fetched
 
     def shard_shared_names(self, shard: Shard) -> list[str]:
         names: list[str] = []
@@ -144,18 +156,17 @@ class HostModelStore:
     # -- shared ------------------------------------------------------------
     def accumulate_shared_grads(self, grads: dict[str, Any]) -> int:
         """Fetch shared-param grads and add them on the host; returns the
-        bytes fetched."""
+        bytes fetched.  Consumes the grads' device arrays."""
+        grads = move_to_host({n: g for n, g in grads.items() if g is not None})
         fetched = 0
         for name, g in grads.items():
-            if g is None:
-                continue
             fetched += tree_bytes(g)
             if name in self.shared_grad_acc:
                 self.shared_grad_acc[name] = jax.tree.map(
-                    lambda a, b: a + np.asarray(b),
-                    self.shared_grad_acc[name], g)
+                    np.add, self.shared_grad_acc[name], g)
+                self.host_copied_bytes += tree_bytes(g)
             else:
-                self.shared_grad_acc[name] = to_host(g)
+                self.shared_grad_acc[name] = g
         return fetched
 
     def step_shared(self) -> int:
@@ -168,9 +179,10 @@ class HostModelStore:
             p = to_device(sg.resolve_ref(self.params, ref))
             s = to_device(self.shared_opt[name])
             new_p, new_s = opt.update(self.opt_cfg, p, to_device(g), s)
-            new_p = move_to_host(new_p)
-            sg.update_with_ref(self.params, ref, new_p)
-            self.shared_opt[name] = move_to_host(new_s)
+            self.shared_opt[name] = None      # stale, as in demote_shard
+            new_p, self.shared_opt[name] = move_to_host((new_p, new_s))
+            self.host_copied_bytes += sg.update_with_ref(
+                self.params, ref, new_p)
             fetched += tree_bytes(new_p) + tree_bytes(self.shared_opt[name])
         self.shared_grad_acc = {}
         return fetched

@@ -8,12 +8,14 @@ from collections import Counter
 from pathlib import Path
 
 import jax
+import numpy as np
 import pytest
 
 from conftest import make_loader
 from repro.api import HydraConfig, Session, TrainJob
 from repro.configs import get_config
 from repro.core import shard_graph as sg
+from repro.core import spilling
 from repro.core.partitioner import tree_bytes
 from repro.core.spilling import DeviceMemory
 
@@ -145,6 +147,48 @@ def test_demoted_bytes_count_what_one_step_fetches():
         store.shard_transfer_bytes(s) for s in shards) * 2
     assert stats.n_demotions == 2 * len(shards)
     assert session.devices[0].resident_bytes == 0
+
+
+def _old_move_to_host(tree):
+    """The fetch as it was: one blocking ``np.array`` copy per leaf."""
+    def move(a):
+        host = np.array(a)
+        if isinstance(a, jax.Array):
+            a.delete()
+        return host
+    return jax.tree.map(move, tree)
+
+
+def _two_steps(session):
+    (ex,) = session.train_execs
+    copied = []
+    for _ in range(STEPS):
+        session.run(max_units=2 * len(ex.partition.shards))
+        copied.append(session.devices[0].stats.host_copied_bytes)
+    state = (ex.store.params, ex.store.opt, ex.store.shared_opt)
+    return ex, list(ex.losses), jax.tree.leaves(state), copied
+
+
+def test_two_steps_match_the_old_fetch_and_count_each_host_copy(monkeypatch):
+    """Two full steps give the losses and the host store, bit for bit, of
+    the same steps fetched the old way; one step copies on the host its
+    layers' weights (into the stack) and the sum of the shared gradients,
+    and keeps the moments, the final norm and the shared step's fetch as
+    they land."""
+    ex, losses, state, copied = _two_steps(_session())
+    with monkeypatch.context() as m:
+        m.setattr(spilling, "move_to_host", _old_move_to_host)
+        _, old_losses, old_state, _ = _two_steps(_session())
+    assert len(losses) == STEPS and losses == old_losses
+    assert len(state) == len(old_state)
+    for a, b in zip(state, old_state):
+        np.testing.assert_array_equal(a, b, strict=True)
+    store, shards = ex.store, ex.partition.shards
+    embed = tree_bytes(sg.resolve_ref(store.params,
+                                      store.plan.shared_refs["embed"]))
+    n_grads = sum(1 for s in shards if store.shard_shared_names(s))
+    one_step = tree_bytes(store.params["layers"]) + (n_grads - 1) * embed
+    assert n_grads == 2 and copied == [one_step, 2 * one_step]
 
 
 def test_charge_demotion_books_only_what_moved():

@@ -86,3 +86,69 @@ def test_transfer_bytes_accounting():
         own_only = sum(pt.tree_bytes(p) for p in store._own_params(shard)
                        if p is not None)
         assert tb >= 2 * own_only
+
+
+def _demote_every_shard_changed(store, part):
+    """Promote each shard, move its params and moments on the device (as a
+    step would), fetch the result the old way (``np.array`` per leaf) for
+    reference, then demote it.  Returns the reference and the device
+    arrays the demotion consumed."""
+    bump = jax.jit(lambda t: jax.tree.map(lambda a: a + 1, t))
+    refs, consumed = {}, []
+    for shard in part.shards:
+        own, _, opt_state = store.promote_shard(shard)
+        new_own, new_opt = bump((own, opt_state))
+        refs[shard.index] = jax.tree.map(np.array, (new_own, new_opt))
+        consumed += jax.tree.leaves((new_own, new_opt))
+        store.demote_shard(shard, new_own, new_opt)
+    return refs, consumed
+
+
+def _assert_store_matches(store, plan, part, refs):
+    for shard in part.shards:
+        ref_own, ref_opt = refs[shard.index]
+        for k, i in enumerate(range(shard.seg_lo, shard.seg_hi)):
+            pref = plan.segments[i].param_ref
+            if pref is None:
+                continue
+            got = sg.resolve_ref(store.params, pref)
+            for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref_own[k])):
+                np.testing.assert_array_equal(a, b, strict=True)
+        for a, b in zip(jax.tree.leaves(store.opt[shard.index]),
+                        jax.tree.leaves(ref_opt)):
+            np.testing.assert_array_equal(a, b, strict=True)
+
+
+def test_demote_writes_into_the_same_writable_stacked_store():
+    cfg, plan, part, store, params = _store()
+    assert len(part.shards) > 1
+    stacked = jax.tree.leaves(store.params["layers"])
+    _demote_every_shard_changed(store, part)
+    after = jax.tree.leaves(store.params["layers"])
+    assert all(a is b for a, b in zip(stacked, after))
+    assert all(a.flags.writeable for a in after)
+
+
+def test_demote_is_bit_identical_to_the_old_fetch_and_outlives_the_device():
+    cfg, plan, part, store, params = _store()
+    refs, consumed = _demote_every_shard_changed(store, part)
+    _assert_store_matches(store, plan, part, refs)
+    # the moments are kept as they landed, read-only
+    assert not any(a.flags.writeable for s in part.shards
+                   for a in jax.tree.leaves(store.opt[s.index]))
+    assert consumed and all(a.is_deleted() for a in consumed)
+    # new device buffers where the freed ones were; the host values stand
+    churn = [jnp.full(a.shape, -7, a.dtype) for a in consumed]
+    jax.block_until_ready(churn)
+    _assert_store_matches(store, plan, part, refs)
+
+
+def test_demote_host_copies_are_the_stacked_param_bytes():
+    cfg, plan, part, store, params = _store()
+    before = store.host_copied_bytes
+    _demote_every_shard_changed(store, part)
+    # every layer sits in one shard and is copied once into the stack; the
+    # final norm and the moments are kept as they land
+    layer_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                      for a in jax.tree.leaves(store.params["layers"]))
+    assert store.host_copied_bytes - before == layer_bytes
