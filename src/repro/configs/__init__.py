@@ -14,6 +14,7 @@ from repro.configs import yi_34b                 # noqa: F401
 from repro.configs import command_r_plus_104b    # noqa: F401
 from repro.configs import zamba2_1_2b            # noqa: F401
 from repro.configs import whisper_medium         # noqa: F401
+from repro.configs import moonlight_16b_a3b      # noqa: F401
 # the paper's own workloads
 from repro.configs import paper_workloads        # noqa: F401
 

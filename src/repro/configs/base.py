@@ -17,7 +17,7 @@ import jax.numpy as jnp
 @dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str                       # dense | moe | ssm | hybrid | vlm | audio
+    family: str       # dense | moe | mla_moe | ssm | hybrid | vlm | audio
     n_layers: int
     d_model: int
     n_heads: int
@@ -37,9 +37,22 @@ class ArchConfig:
     attn_impl: str = "xla"            # xla | pallas | pallas_interpret
 
     # MoE
-    n_experts: int = 0
+    n_experts: int = 0                # routed experts (the router's width)
     top_k: int = 0
     capacity_factor: float = 1.25
+
+    # fine-grained MoE with latent attention (mla_moe: DeepSeek-V3 class)
+    kv_lora_rank: int = 0             # latent width of keys and values
+    qk_nope_head_dim: int = 0         # per-head q/k part without rope
+    qk_rope_head_dim: int = 0         # q/k rope part; k's is shared by heads
+    v_head_dim: int = 0
+    moe_d_ff: int = 0                 # routed expert width (d_ff: dense MLP)
+    n_shared_experts: int = 0         # one SwiGLU of n_shared * moe_d_ff
+    first_k_dense: int = 0            # leading dense layers
+    routed_scaling_factor: float = 1.0
+    n_experts_held: int = 0           # routed experts computed here (0: all)
+    expert_offset: int = 0            # first held expert
+    norm_eps: float = 1e-6
 
     # SSM / recurrent
     ssm_state: int = 0                # Mamba2 state dim N
@@ -93,7 +106,33 @@ class ArchConfig:
         return mult * self.d_model * self.d_ff
 
     @property
+    def mla_params(self) -> int:
+        d, nh, r = self.d_model, self.n_heads, self.kv_lora_rank
+        nope, rope, v = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                         self.v_head_dim)
+        return (d * nh * (nope + rope) + d * (r + rope) + r
+                + r * nh * (nope + v) + nh * v * d)
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    def mla_layer_params(self, dense: bool, active: bool = False) -> int:
+        """One mla_moe block, norms included; ``active`` counts the routed
+        experts a token uses on average here: top_k x held / n_experts."""
+        d, f = self.d_model, self.moe_d_ff
+        base = self.mla_params + 2 * d
+        if dense:
+            return base + 3 * d * self.d_ff
+        routed = 3 * d * f * (self.top_k * self.experts_held / self.n_experts
+                              if active else self.experts_held)
+        return int(base + routed + 3 * d * f * self.n_shared_experts
+                   + (d + 1) * self.n_experts)
+
+    @property
     def layer_params(self) -> int:
+        if self.family == "mla_moe":
+            return self.mla_layer_params(dense=False)
         if self.family == "moe":
             return self.attn_params + self.n_experts * self.mlp_params + \
                 self.d_model * self.n_experts  # router
@@ -102,8 +141,16 @@ class ArchConfig:
             return 2 * self.d_model * d_in + d_in * (2 * self.ssm_state + 2)
         return self.attn_params + self.mlp_params
 
+    def _mla_moe_params(self, active: bool) -> int:
+        k = self.first_k_dense
+        return (k * self.mla_layer_params(dense=True)
+                + (self.n_layers - k) * self.mla_layer_params(False, active)
+                + 2 * self.vocab_size * self.d_model + self.d_model)
+
     @property
     def n_params(self) -> int:
+        if self.family == "mla_moe":
+            return self._mla_moe_params(active=False)
         emb = self.vocab_size * self.d_model
         body = self.n_layers * self.layer_params
         if self.is_encoder_decoder:
@@ -113,6 +160,8 @@ class ArchConfig:
     @property
     def n_active_params(self) -> int:
         """Per-token active params (MoE counts top_k experts only)."""
+        if self.family == "mla_moe":
+            return self._mla_moe_params(active=True)
         if self.family != "moe":
             return self.n_params
         dense_layer = self.attn_params + self.top_k * self.mlp_params

@@ -111,7 +111,7 @@ def spilled_forward(store, fns, partition, batch, *, on_shard=None):
     for shard in partition.shards:
         own, shared, _ = store.promote_shard(shard)
         moved += store.shard_transfer_bytes(shard, train=False)
-        out, _ = fns.fwd(shard)(own, shared, act, batch)
+        out = fns.fwd(shard)(own, shared, act, batch)[0]
         act = out
         if on_shard is not None:
             on_shard(shard)

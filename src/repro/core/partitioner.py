@@ -68,7 +68,11 @@ def tree_bytes(tree) -> int:
 
 
 def _act_width(cfg) -> int:
-    """Bytes per (batch·seq) element of the inter-segment activation."""
+    """Bytes per (batch·seq) element of the inter-segment activation; for
+    a latent-attention block, of its widest live set instead: q, k and v
+    in f32 (its scores are blockwise, its expert buffer narrower)."""
+    if cfg.family == "mla_moe":
+        return cfg.n_heads * (2 * cfg.head_dim + cfg.v_head_dim) * 4
     w = cfg.d_model * jnp.dtype(cfg.dtype).itemsize
     if cfg.family == "audio":
         w *= 2     # decoder segments also carry enc passthrough

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models import encdec, hybrid, moe, ssm, transformer
+from repro.models import encdec, hybrid, mla_moe, moe, ssm, transformer
 from repro.models import layers as nn
 from repro.training.losses import softmax_xent
 
@@ -165,6 +165,40 @@ def _moe_plan(cfg) -> ShardPlan:
     return ShardPlan(cfg, segs, {"embed": ("embed",)}, _xent_loss)
 
 
+def _mla_moe_plan(cfg) -> ShardPlan:
+    """Untied: the embedding and the head are each one segment's own params,
+    and nothing is shared.  MoE segments hand the rows their held experts
+    computed, and in how many passes, out of the chain as ``act["counts"]``
+    (``ShardFunctions``)."""
+    n_dense = cfg.first_k_dense
+
+    def embed_apply(cfg, own, shared, act, batch):
+        return {"x": nn.embed(own, batch["tokens"], cfg.dtype)}
+
+    def dense_apply(cfg, own, shared, act, batch):
+        return {"x": mla_moe.apply_dense_range(cfg, own, act["x"])}
+
+    def layer_apply(cfg, own, shared, act, batch):
+        x, counts = mla_moe.apply_layer_range(cfg, own, act["x"])
+        return {"x": x, "counts": counts}
+
+    def head_apply(cfg, own, shared, act, batch):
+        return {"loss": mla_moe.head_loss(cfg, own, act["x"],
+                                          batch["labels"])}
+
+    segs = [Segment("embed", ("embed",), (), embed_apply, 0.1)]
+    for i in range(cfg.n_layers):
+        if i < n_dense:
+            segs.append(Segment(f"dense{i}", ("stack_slice", "dense", i,
+                                              i + 1), (), dense_apply))
+        else:
+            j = i - n_dense
+            segs.append(Segment(f"layer{i}", ("stack_slice", "layers", j,
+                                              j + 1), (), layer_apply))
+    segs.append(Segment("head", ("head",), (), head_apply, 0.5))
+    return ShardPlan(cfg, segs, {}, lambda cfg, act, batch: act["loss"])
+
+
 def _ssm_plan(cfg) -> ShardPlan:
     def embed_apply(cfg, own, shared, act, batch):
         return {"x": nn.embed(shared["embed"], batch["tokens"], cfg.dtype)}
@@ -294,6 +328,8 @@ def build_plan(cfg) -> ShardPlan:
         return _dense_plan(cfg)
     if fam == "moe":
         return _moe_plan(cfg)
+    if fam == "mla_moe":
+        return _mla_moe_plan(cfg)
     if fam == "ssm":
         return _ssm_plan(cfg)
     if fam == "hybrid":

@@ -122,11 +122,21 @@ class ShardFunctions:
         self._step = _FN_CACHE[step_key]
 
     def _chain(self, shard: Shard, own, shared, act, batch):
+        """The shard's segments in order -> (act, counts).  A segment may
+        add program counters to its act under ``"counts"``; they leave the
+        chain here, summed over the shard, and never flow into the next
+        segment or the backward pass."""
+        counts = None
         for k, i in enumerate(range(shard.seg_lo, shard.seg_hi)):
             seg = self.plan.segments[i]
             seg_shared = {n: shared[n] for n in seg.shared}
             act = seg.apply(self.cfg, own[k], seg_shared, act, batch)
-        return act
+            if "counts" in act:
+                act = dict(act)
+                c = act.pop("counts")
+                counts = c if counts is None else jax.tree.map(
+                    jnp.add, counts, c)
+        return act, counts
 
     def fwd(self, shard: Shard):
         if shard.index not in self._fwd:
@@ -140,11 +150,12 @@ class ShardFunctions:
         return self._fwd[shard.index]
 
     def _fwd_impl(self, shard, own, shared, act, batch):
-        out = self._chain(shard, own, shared, act, batch)
+        """-> (out, loss or None, counts or None)."""
+        out, counts = self._chain(shard, own, shared, act, batch)
         if shard.index == len(self.partition.shards) - 1:
             loss = self.plan.loss(self.cfg, out, batch)
-            return out, loss
-        return out, None
+            return out, loss, counts
+        return out, None, counts
 
     def bwd(self, shard: Shard):
         if shard.index not in self._bwd:
@@ -158,7 +169,7 @@ class ShardFunctions:
 
     def _bwd_last_impl(self, shard, own, shared, act_in, batch):
         def f(o, s, a):
-            out = self._chain(shard, o, s, a, batch)
+            out, _ = self._chain(shard, o, s, a, batch)
             return self.plan.loss(self.cfg, out, batch)
 
         loss, vjp = jax.vjp(f, own, shared, act_in)
@@ -167,7 +178,7 @@ class ShardFunctions:
 
     def _bwd_impl(self, shard, own, shared, act_in, cot_out, batch):
         def f(o, s, a):
-            return self._chain(shard, o, s, a, batch)
+            return self._chain(shard, o, s, a, batch)[0]
 
         _, vjp = jax.vjp(f, own, shared, act_in)
         g_own, g_shared, g_act = vjp(cot_out)
@@ -204,6 +215,10 @@ class ModelExec:
     saved_cot: Any = None                  # cotangent flowing backward
     losses: list = field(default_factory=list)
     done: bool = False
+    # program counters of this step's forward units, and of steps whose
+    # loss has been read (the executor books those in TransferStats)
+    pending_counts: list = field(default_factory=list)
+    ready_counts: list = field(default_factory=list)
 
     def build_minibatch_queue(self):
         shards = self.partition.shards
@@ -326,10 +341,10 @@ class SharpExecutor:
                 own, shared, _ = m.store.promote_shard(shard)
                 fwd = m.fns.fwd(shard)
                 acts[shard.index] = act
-                out, _ = fwd(own, shared, act, batch)       # compile run
+                out = fwd(own, shared, act, batch)[0]       # compile run
                 jax.block_until_ready(out)
                 t0 = time.perf_counter()
-                out, _ = fwd(own, shared, act, batch)
+                out = fwd(own, shared, act, batch)[0]
                 jax.block_until_ready(out)
                 shard.fwd_runtime = max(time.perf_counter() - t0, 1e-7)
                 act = out
@@ -371,10 +386,17 @@ class SharpExecutor:
             # entry activation is the checkpoint this shard's backward reuses
             m.saved_acts[("entry", shard.index)] = act_in
             with TraceAnnotation("sharp.dispatch", dir="fwd", **ids):
-                out, loss = m.fns.fwd(shard)(own, shared, act_in, batch)
+                out, loss, counts = m.fns.fwd(shard)(own, shared, act_in,
+                                                     batch)
+            if counts is not None:
+                m.pending_counts.append(counts)
             if shard.index == len(m.partition.shards) - 1:
                 with TraceAnnotation("sharp.loss_read", dir="fwd", **ids):
                     m.losses.append(float(loss))
+                # the loss read waited for every forward unit of the step:
+                # their counters are on the device and small
+                m.ready_counts += m.pending_counts
+                m.pending_counts = []
             m.saved_acts[("exit", shard.index)] = out
         else:
             act_in = m.saved_acts[("entry", shard.index)]
@@ -487,6 +509,8 @@ class SharpExecutor:
             fetched = self._execute_unit(m, unit)
             self.units_executed += 1
             dev.charge_demotion(shard_bytes, moved=fetched)
+            while m.ready_counts:
+                dev.stats.add_counters(m.ready_counts.pop(0))
             if on_unit is not None:
                 on_unit(UnitEvent(
                     model_id=m.model_id, shard_index=unit.shard.index,

@@ -70,6 +70,17 @@ class TransferStats:
     kv_prefetched_bytes: int = 0
     n_kv_demotions: int = 0
     n_kv_prefetches: int = 0
+    # program counters that forward units return beside their output,
+    # summed over steps: name -> int array (``expert_rows``: rows the held
+    # experts' grouped matmuls computed, per held expert, forward only;
+    # ``expert_passes``: the routed passes that computed them)
+    counters: dict = field(default_factory=dict)
+
+    def add_counters(self, counts: dict) -> None:
+        for name, value in counts.items():
+            value = np.asarray(value, np.int64)
+            self.counters[name] = (self.counters[name] + value
+                                   if name in self.counters else value)
 
     def total_bytes(self) -> int:
         return (self.promoted_bytes + self.demoted_bytes

@@ -139,15 +139,16 @@ def _project_qkv(params: Params, x: jnp.ndarray, xkv: jnp.ndarray, cfg):
     return q, k, v
 
 
-# q-chunking threshold: above this many score elements per (b·h) row-block,
-# the XLA path scans over query chunks so the (sq, skv) score matrix is never
-# materialized whole (flash-style; the Pallas kernel is the TPU fast path).
-_SDPA_CHUNK_ELEMS = 4096 * 4096
-_SDPA_Q_CHUNK = 1024
+# dense-path limit: above this many score elements (b·h·sq·skv) the scores
+# are never held whole; attention runs blockwise, forward and backward
+_SDPA_DENSE_ELEMS = 2 ** 28
+_SDPA_BLOCK = 512
+_NEG = -1e30
 
 
 def _sdpa_dense(q, k, v, scale, qpos, kpos, causal, window):
-    """q: (b, sq, nkv, g, hd) grouped; k/v: (b, skv, nkv, hd)."""
+    """q: (b, sq, nkv, g, hd) grouped; k: (b, skv, nkv, hd); v: (b, skv,
+    nkv, hv)."""
     logits = jnp.einsum("bqkgh,bskh->bkgqs", q.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
     mask = jnp.ones((qpos.shape[0], kpos.shape[0]), bool)
@@ -155,10 +156,148 @@ def _sdpa_dense(q, k, v, scale, qpos, kpos, causal, window):
         mask &= kpos[None, :] <= qpos[:, None]
     if window is not None:
         mask &= kpos[None, :] > qpos[:, None] - window
-    logits = jnp.where(mask[None, None, None], logits, -1e30)
+    logits = jnp.where(mask[None, None, None], logits, _NEG)
     probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgqs,bskh->bqkgh", probs, v.astype(jnp.float32))
+    out = jnp.einsum("bkgqs,bskv->bqkgv", probs, v.astype(jnp.float32))
     return out
+
+
+def _block_mask(qp, kp, kok, causal, window):
+    """(Bq, Bk) mask of one block pair; ``kok`` marks real (unpadded) keys."""
+    mask = jnp.broadcast_to(kok[None, :], (qp.shape[0], kp.shape[0]))
+    if causal:
+        mask &= kp[None, :] <= qp[:, None]
+    if window is not None:
+        mask &= kp[None, :] > qp[:, None] - window
+    return mask
+
+
+def _block_needed(qp, kp, causal, window):
+    """False when the mask rules out every pair of the block: the block is
+    skipped, not computed."""
+    need = jnp.bool_(True)
+    if causal:
+        need &= jnp.min(kp) <= jnp.max(qp)
+    if window is not None:
+        need &= jnp.max(kp) > jnp.min(qp) - window
+    return need
+
+
+def _blocks(x, n):
+    """(b, n * B, ...) -> (n, b, B, ...)."""
+    x = x.reshape(x.shape[0], n, -1, *x.shape[2:])
+    return jnp.moveaxis(x, 1, 0)
+
+
+def _scores(qi, kj, scale, mask):
+    s = jnp.einsum("bqkgh,bskh->bkgqs", qi.astype(jnp.float32),
+                   kj.astype(jnp.float32)) * scale
+    return jnp.where(mask[None, None, None], s, _NEG)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _flash(q, k, v, qpos, kpos, kok, scale, causal, window):
+    return _flash_fwd(q, k, v, qpos, kpos, kok, scale, causal, window)[0]
+
+
+def _flash_fwd(q, k, v, qpos, kpos, kok, scale, causal, window):
+    """Online softmax over key blocks, one query block at a time.  Inputs
+    are in blocks: q (nq, b, Bq, nkv, g, hd), k/v (nk, b, Bk, nkv, .),
+    positions (n, B).  Returns out (nq, b, nkv, g, Bq, hv) f32."""
+    nk = k.shape[0]
+    _, b, bq, nkv, g, _ = q.shape
+    hv = v.shape[-1]
+
+    def q_body(_, xs):
+        qi, qp = xs
+
+        def k_body(j, carry):
+            def compute(carry):
+                m, l, acc = carry
+                s = _scores(qi, k[j], scale,
+                            _block_mask(qp, kpos[j], kok[j], causal, window))
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+                p = jnp.exp(s - m_new[..., None])
+                corr = jnp.exp(m - m_new)
+                acc = acc * corr[..., None] + jnp.einsum(
+                    "bkgqs,bskv->bkgqv", p, v[j].astype(jnp.float32))
+                return m_new, l * corr + jnp.sum(p, axis=-1), acc
+            return jax.lax.cond(_block_needed(qp, kpos[j], causal, window),
+                                compute, lambda c: c, carry)
+
+        m0 = jnp.full((b, nkv, g, bq), _NEG, jnp.float32)
+        carry = (m0, jnp.zeros_like(m0),
+                 jnp.zeros((b, nkv, g, bq, hv), jnp.float32))
+        m, l, acc = jax.lax.fori_loop(0, nk, k_body, carry)
+        return None, (acc / l[..., None], m + jnp.log(l))
+
+    _, (out, lse) = jax.lax.scan(q_body, None, (q, qpos))
+    return out, (q, k, v, qpos, kpos, kok, out, lse)
+
+
+def _flash_bwd(scale, causal, window, res, dout):
+    """Recompute each block's probabilities from the saved log-sum-exp; one
+    pass over key blocks gives dk, dv, and dq accumulates in place."""
+    q, k, v, qpos, kpos, kok, out, lse = res
+    nq = q.shape[0]
+    delta = jnp.sum(dout * out, axis=-1)               # (nq, b, nkv, g, Bq)
+
+    def k_body(dq, xs):
+        kj, vj, kp, ko = xs
+        kf, vf = kj.astype(jnp.float32), vj.astype(jnp.float32)
+
+        def q_body(i, carry):
+            def compute(carry):
+                dq, dk, dv = carry
+                qi = q[i]
+                s = _scores(qi, kj, scale,
+                            _block_mask(qpos[i], kp, ko, causal, window))
+                p = jnp.exp(s - lse[i][..., None])
+                dv = dv + jnp.einsum("bkgqs,bkgqv->bskv", p, dout[i])
+                dp = jnp.einsum("bkgqv,bskv->bkgqs", dout[i], vf)
+                ds = p * (dp - delta[i][..., None]) * scale
+                dq = dq.at[i].add(jnp.einsum("bkgqs,bskh->bqkgh", ds, kf))
+                dk = dk + jnp.einsum("bkgqs,bqkgh->bskh", ds,
+                                     qi.astype(jnp.float32))
+                return dq, dk, dv
+            return jax.lax.cond(_block_needed(qpos[i], kp, causal, window),
+                                compute, lambda c: c, carry)
+
+        dq, dk, dv = jax.lax.fori_loop(
+            0, nq, q_body, (dq, jnp.zeros(kj.shape, jnp.float32),
+                            jnp.zeros(vj.shape, jnp.float32)))
+        return dq, (dk.astype(kj.dtype), dv.astype(vj.dtype))
+
+    dq, (dk, dv) = jax.lax.scan(k_body, jnp.zeros(q.shape, jnp.float32),
+                                (k, v, kpos, kok))
+    return dq.astype(q.dtype), dk, dv, None, None, None
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def blockwise_attention(q, k, v, scale, qpos, kpos, causal, window,
+                        block: int = _SDPA_BLOCK):
+    """Attention whose scores live one (block x block) tile at a time in the
+    forward and the backward pass (flash-style, in XLA).  Same arguments and
+    result as ``_sdpa_dense``; sequences are padded to whole blocks."""
+    b, sq = q.shape[:2]
+    skv = k.shape[1]
+    nq, nk = -(-sq // block), -(-skv // block)
+    pq, pk = nq * block - sq, nk * block - skv
+
+    def pad(x, n):
+        return jnp.pad(x, [(0, 0), (0, n)] + [(0, 0)] * (x.ndim - 2))
+    qpos = jnp.pad(qpos, (0, pq), mode="edge")
+    kok = jnp.arange(nk * block) < skv
+    kpos = jnp.pad(kpos, (0, pk), mode="edge")
+    out = _flash(_blocks(pad(q, pq), nq), _blocks(pad(k, pk), nk),
+                 _blocks(pad(v, pk), nk), qpos.reshape(nq, block),
+                 kpos.reshape(nk, block), kok.reshape(nk, block),
+                 scale, causal, window)
+    # (nq, b, nkv, g, Bq, hv) -> (b, sq, nkv, g, hv)
+    out = jnp.transpose(out, (1, 0, 4, 2, 3, 5))
+    return out.reshape(b, nq * block, *out.shape[3:])[:, :sq]
 
 
 def sdpa(q, k, v, *, causal: bool, window: Optional[int] = None,
@@ -167,9 +306,10 @@ def sdpa(q, k, v, *, causal: bool, window: Optional[int] = None,
          impl: str = "xla") -> jnp.ndarray:
     """Scaled dot-product attention with GQA broadcast.
 
-    q: (b, sq, nh, hd); k/v: (b, skv, nkv, hd).  nh % nkv == 0.
-    ``window``: sliding-window size (None = full).  Positions default to
-    arange; decode passes explicit positions.
+    q: (b, sq, nh, hd); k: (b, skv, nkv, hd); v: (b, skv, nkv, hv), whose
+    head size may differ from q's.  nh % nkv == 0.  ``window``:
+    sliding-window size (None = full).  Positions default to arange;
+    decode passes explicit positions.  Scores in f32, scaled by hd^-1/2.
     """
     if impl in ("pallas", "pallas_interpret") and causal and q.shape[1] > 1:
         from repro.kernels import ops as kops
@@ -177,38 +317,26 @@ def sdpa(q, k, v, *, causal: bool, window: Optional[int] = None,
             q, k, v, causal=True, window=window,
             interpret=(impl == "pallas_interpret"))
     b, sq, nh, hd = q.shape
-    skv, nkv = k.shape[1], k.shape[2]
+    skv, nkv, hv = k.shape[1], k.shape[2], v.shape[-1]
     groups = nh // nkv
-    qg = q.reshape(b, sq, nkv, groups, hd)
     scale = 1.0 / math.sqrt(hd)
     qpos = (q_positions if q_positions is not None
             else jnp.arange(sq))
     kpos = (kv_positions if kv_positions is not None
             else jnp.arange(skv))
 
+    qg = q.reshape(b, sq, nkv, groups, hd)
+
     from repro.sharding.context import constrain_q_seq
     qg = constrain_q_seq(qg.reshape(b, sq, nh, hd)).reshape(
         b, sq, nkv, groups, hd)
 
-    if sq * skv <= _SDPA_CHUNK_ELEMS or sq % _SDPA_Q_CHUNK != 0:
+    if b * nh * sq * skv <= _SDPA_DENSE_ELEMS:
         out = _sdpa_dense(qg, k, v, scale, qpos, kpos, causal, window)
-        return out.reshape(b, sq, nh, hd).astype(q.dtype)
-
-    # chunked path: scan over query blocks; score rows live one block at a
-    # time (the XLA analogue of the Pallas flash kernel, fully differentiable)
-    nq = sq // _SDPA_Q_CHUNK
-    qc = qg.reshape(b, nq, _SDPA_Q_CHUNK, nkv, groups, hd).transpose(
-        1, 0, 2, 3, 4, 5)
-    qpc = qpos.reshape(nq, _SDPA_Q_CHUNK)
-
-    def body(_, inp):
-        qb, qp = inp
-        ob = _sdpa_dense(qb, k, v, scale, qp, kpos, causal, window)
-        return None, ob
-
-    _, out = jax.lax.scan(body, None, (qc, qpc))
-    out = out.transpose(1, 0, 2, 3, 4, 5).reshape(b, sq, nkv, groups, hd)
-    return out.reshape(b, sq, nh, hd).astype(q.dtype)
+    else:
+        out = blockwise_attention(qg, k, v, scale, qpos, kpos, causal,
+                                  window)
+    return out.reshape(b, sq, nh, hv).astype(q.dtype)
 
 
 def attention(params: Params, x: jnp.ndarray, cfg, *,

@@ -142,6 +142,7 @@ _FAMILY_MODULES = {
     "dense": "repro.models.transformer",
     "vlm": "repro.models.transformer",
     "moe": "repro.models.moe",
+    "mla_moe": "repro.models.mla_moe",
     "ssm": "repro.models.ssm",
     "hybrid": "repro.models.hybrid",
     "audio": "repro.models.encdec",

@@ -31,13 +31,13 @@ def test_sdpa_chunked_matches_dense():
         q.reshape(1, 2048, 2, 1, 16), k, v, 1 / 4.0,
         jnp.arange(2048), jnp.arange(2048), True, None
     ).reshape(1, 2048, 2, 16)
-    # force the chunked path
-    old = nn._SDPA_CHUNK_ELEMS
-    nn._SDPA_CHUNK_ELEMS = 1024 * 1024
+    # force the blockwise path (scores never held whole)
+    old = nn._SDPA_DENSE_ELEMS
+    nn._SDPA_DENSE_ELEMS = 1024 * 1024
     try:
         chunked = nn.sdpa(q, k, v, causal=True)
     finally:
-        nn._SDPA_CHUNK_ELEMS = old
+        nn._SDPA_DENSE_ELEMS = old
     np.testing.assert_allclose(chunked, dense.astype(chunked.dtype),
                                rtol=1e-5, atol=1e-5)
 

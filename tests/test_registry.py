@@ -34,6 +34,7 @@ FAMILY_ARCH = {
     "dense": "qwen3-0.6b",
     "vlm": "llava-next-mistral-7b",
     "moe": "mixtral-8x22b",
+    "mla_moe": "moonlight-16b-a3b",
     "ssm": "xlstm-350m",
     "hybrid": "zamba2-1.2b",
     "audio": "whisper-medium",
